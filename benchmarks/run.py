@@ -51,7 +51,7 @@ MODULES = [
     ("serving_qos", "serving-plane QoS: adaptive batching + chaining"),
     ("faults", "crash-under-load: fault injection + checkpoint recovery, "
                "time-to-detect/recover/SLO-recovery on both backends"),
-    ("kernels", "Pallas kernel validation vs oracles"),
+    ("kernels", "compiled Pallas kernels vs oracles (TPU only)"),
     ("roofline", "dry-run roofline terms per (arch x shape)"),
 ]
 
@@ -87,6 +87,9 @@ def main() -> None:
                     help="cProfile each module and write BENCH_<module>.prof "
                          "next to the JSON artifact")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     # preflight WARNs (graph_check/feasibility, e.g. NS-F002 "goal only
     # reachable near max scale-out") are advisory and never raise — surface

@@ -20,18 +20,6 @@ def run(quick: bool = True):
     params = model.init_params(jax.random.PRNGKey(0))
     spec = RequestSpec(rate_per_s=30.0, prompt_len=16, gen_len=4,
                        vocab=cfg.vocab_size)
-    # warm the jit caches for the power-of-two buckets so compile time does
-    # not pollute the latency measurements
-    import numpy as np
-    import jax.numpy as jnp
-    for b in (1, 2, 4, 8, 16, 32, 64, 128):
-        batch = {"tokens": jnp.zeros((b, spec.prompt_len), jnp.int32)}
-        logits, cache = jax.jit(
-            lambda p, bt: model.prefill(p, bt, spec.prompt_len + spec.gen_len + 8)
-        )(params, batch)
-        tok = jnp.zeros((b,), jnp.int32)
-        jax.jit(model.decode_step)(params, cache, tok,
-                                   jnp.full((b,), spec.prompt_len, jnp.int32))
 
     dur = 40_000.0 if quick else 90_000.0
     rows = []
@@ -45,6 +33,9 @@ def run(quick: bool = True):
     ):
         srv = QoSServer(model, params, spec, latency_limit_ms=400.0,
                         measurement_interval_ms=500.0, **kw)
+        # compile every batch bucket first, so that compile time does not
+        # pollute the latency measurements
+        srv.warmup()
         res = srv.run(dur)
         rows.append((
             f"serve_{name}",

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (ALL_TO_ALL, POINTWISE, JobConstraint, JobGraph,
                         JobSequence, JobVertex, SourceSpec, StreamEngine)
 
@@ -74,6 +75,7 @@ def main() -> None:
     ap.add_argument("--duration", type=float, default=20.0)
     ap.add_argument("--no-qos", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     jg = JobGraph("media")
     jg.add_vertex(JobVertex("Partitioner", 2, is_source=True))

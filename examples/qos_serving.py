@@ -10,6 +10,7 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serving import QoSServer, RequestSpec
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--rate", type=float, default=30.0)
     ap.add_argument("--slo-ms", type=float, default=400.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config("qwen3-1.7b", smoke=True)
     model = build_model(cfg)

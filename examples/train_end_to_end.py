@@ -10,6 +10,7 @@ import tempfile
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import train
 
 
@@ -19,6 +20,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     args = ap.parse_args()
+    use_compile_cache()
 
     # ~100M params: d_model 512, 8 layers, byte-level vocab
     overrides = dict(

@@ -1,4 +1,6 @@
-"""jit'd wrapper for the flash-decoding kernel (interpret mode on CPU)."""
+"""jit'd wrapper for the flash-decoding kernel.  ``interpret`` has no
+default: a caller picks the Pallas interpreter (CPU) or the compiled kernel
+(TPU) itself."""
 from functools import partial
 
 import jax
@@ -9,7 +11,7 @@ from .decode_attention import INVALID_POS, flash_decode
 
 @partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
 def flash_decode_op(q, k, v, q_positions, kv_positions, *,
-                    window=None, block_k: int = 512, interpret: bool = True):
+                    interpret: bool, window=None, block_k: int = 512):
     B, W = kv_positions.shape
     bk = min(block_k, W)
     pad = (-W) % bk

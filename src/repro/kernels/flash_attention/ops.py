@@ -1,8 +1,8 @@
 """jit'd wrapper: pads sequence dims to block multiples (holes are masked
 via INVALID_POS), dispatches the Pallas kernel, and unpads.
 
-On this CPU container the kernel executes in interpret mode (the Pallas
-interpreter runs the kernel body in Python); on TPU pass interpret=False.
+``interpret`` has no default: a caller picks the Pallas interpreter (CPU)
+or the compiled kernel (TPU) itself.
 """
 from __future__ import annotations
 
@@ -17,9 +17,9 @@ from .flash_attention import INVALID_POS, flash_attention
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention_op(q, k, v, q_positions, kv_positions, *,
-                       causal: bool = True, window: int | None = None,
-                       block_q: int = 128, block_k: int = 128,
-                       interpret: bool = True):
+                       interpret: bool, causal: bool = True,
+                       window: int | None = None,
+                       block_q: int = 128, block_k: int = 128):
     B, Sq, Hq, D = q.shape
     _, Skv, _, _ = k.shape
     bq, bk = min(block_q, max(Sq, 8)), min(block_k, max(Skv, 8))

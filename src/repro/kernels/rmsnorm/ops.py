@@ -1,4 +1,6 @@
-"""jit'd wrapper for the RMSNorm Pallas kernel."""
+"""jit'd wrapper for the RMSNorm Pallas kernel.  ``interpret`` has no
+default: a caller picks the Pallas interpreter (CPU) or the compiled kernel
+(TPU) itself."""
 from functools import partial
 
 import jax
@@ -7,6 +9,6 @@ from .rmsnorm import rmsnorm
 
 
 @partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm_op(x, w, *, eps: float = 1e-6, block_rows: int = 256,
-               interpret: bool = True):
+def rmsnorm_op(x, w, *, interpret: bool, eps: float = 1e-6,
+               block_rows: int = 256):
     return rmsnorm(x, w, eps=eps, block_rows=block_rows, interpret=interpret)

@@ -24,7 +24,9 @@ The paper's two degrees of freedom, re-read for TPU serving (DESIGN.md §2.2):
   countermeasure (scale-out before GiveUp) under the latency SLO.
 
 Pipeline:  Ingress (source) -> Prefill (batch) -> Decode -> Egress (sink).
-Batch shapes are bucketed to powers of two so the jit cache stays bounded.
+Batch shapes are bucketed to powers of two so the jit cache stays bounded;
+``QoSServer.warmup`` compiles every bucket a run can reach before it starts.
+Egress records each answer (``ServingResult.responses``).
 
 Results carry per-Decode-replica **token-throughput** and **KV-cache
 occupancy** gauges (``ServingResult.replica_metrics``) — the saturation
@@ -37,6 +39,7 @@ default request-count telemetry.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -74,6 +77,11 @@ class RequestSpec:
     gen_len: int = 8
     vocab: int = 256
 
+    @property
+    def max_len(self) -> int:
+        """KV-cache slots per request: prompt, generation and 8 spare."""
+        return self.prompt_len + self.gen_len + 8
+
 
 @dataclass
 class ServingResult:
@@ -93,6 +101,12 @@ class ServingResult:
     #: signals live.  Throughput is denominated by each replica's live
     #: duration, so mid-run-spawned replicas report their true rate.
     replica_metrics: dict = field(default_factory=dict)
+    #: requests the generator admitted (emitted into the pipeline)
+    admitted: int = 0
+    #: one record per answer that reached Egress, in arrival order:
+    #: {"request_id", "tokens" (the greedy continuation, gen_len ids),
+    #: "finite" (every prefill and decode logit of its batch was finite)}
+    responses: list = field(default_factory=list)
 
     @property
     def total_token_throughput_per_s(self) -> float:
@@ -134,6 +148,31 @@ def _bucket(n: int) -> int:
     return b
 
 
+def serving_steps(model: Model, max_len: int):
+    """The server's two device programs, jitted: prefill of a bucketed batch
+    of prompts and one decode step against its KV cache of ``max_len``
+    slots.  Both fuse greedy sampling and a finiteness check of their logits
+    into the step, so no separate dispatch reads the logits.
+
+    ``prefill(params, tokens[B, P]) -> (token[B], finite, cache)``
+    ``decode(params, cache, token[B], pos, finite) -> (token[B], finite,
+    cache)``: ``pos`` is the position written this step (a scalar, the same
+    for every row), ``finite`` accumulates over the steps."""
+
+    def prefill(params, tokens):
+        logits, cache = model.prefill(params, {"tokens": tokens}, max_len)
+        return (jnp.argmax(logits, -1).astype(jnp.int32),
+                jnp.isfinite(logits).all(), cache)
+
+    def decode(params, cache, token, pos, finite):
+        pos = jnp.full(token.shape, pos, jnp.int32)
+        logits, cache = model.decode_step(params, cache, token, pos)
+        return (jnp.argmax(logits, -1).astype(jnp.int32),
+                finite & jnp.isfinite(logits).all(), cache)
+
+    return jax.jit(prefill), jax.jit(decode)
+
+
 class QoSServer:
     def __init__(
         self,
@@ -163,7 +202,7 @@ class QoSServer:
         self.params = params
         self.spec = spec
         self.autoscaler = autoscaler
-        self.max_len = spec.prompt_len + spec.gen_len + 8
+        self.max_len = spec.max_len
         #: KV budget per Decode replica (tokens) for the occupancy fraction
         #: fed to the token autoscaler; the default is the session store's
         #: own capacity bound (retention window x max sequence length).
@@ -171,52 +210,36 @@ class QoSServer:
             kv_token_budget_per_replica
             if kv_token_budget_per_replica is not None
             else SESSION_RETENTION * self.max_len)
-        self._jit_prefill = {}
-        self._jit_decode = {}
+        self._prefill, self._decode = serving_steps(model, self.max_len)
         self.batch_sizes: list[int] = []
+        self.responses: list[dict] = []
+        self._admitted = 0
         #: per-replica generated-token counters (replica id -> tokens);
         #: sampled with the KV-cache occupancy gauges into replica_metrics
         self._replica_tokens: dict[str, int] = {}
         self._lock = threading.Lock()
 
-        cfg = model.cfg
         req_bytes = spec.prompt_len * 4 + 16
+        omega_bytes = initial_buffer_bytes * 8
+        #: the largest batch the Ingress buffer can ship: it ships once the
+        #: requests in it reach its capacity, which the buffer policy caps
+        #: at omega_bytes
+        self.max_batch = max(1, -(-omega_bytes // req_bytes))
 
         def prefill_fn(payloads, emit, ctx):
-            reqs = payloads
-            n = len(reqs)
             with self._lock:
-                self.batch_sizes.append(n)
-            bsz = _bucket(n)
-            toks = np.zeros((bsz, spec.prompt_len), np.int32)
-            for i, r in enumerate(reqs):
-                toks[i] = r["tokens"]
-            fn = self._prefill_for(bsz)
-            batch = {"tokens": jnp.asarray(toks)}
-            logits, cache = fn(self.params, batch)
-            emit(
-                {"cache": cache, "logits": logits, "reqs": reqs, "bsz": bsz},
-                size_bytes=n * 64,
-            )
+                self.batch_sizes.append(len(payloads))
+            emit(self._prefill_batch(payloads),
+                 size_bytes=len(payloads) * 64)
 
         def decode_fn(payload, emit, ctx):
-            st = payload
-            bsz, reqs = st["bsz"], st["reqs"]
-            fn = self._decode_for(bsz)
-            cache = st["cache"]
-            tok = jnp.argmax(st["logits"], -1).astype(jnp.int32)
-            out_tokens = [tok]
-            for i in range(spec.gen_len - 1):
-                pos = jnp.full((bsz,), spec.prompt_len + i, jnp.int32)
-                logits, cache = fn(self.params, cache, tok, pos)
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                out_tokens.append(tok)
-            outs = np.stack([np.asarray(t) for t in out_tokens], 1)
+            reqs = payload["reqs"]
+            outs, finite = self._decode_batch(payload)
             with self._lock:
                 rid = ctx.vertex.id
                 self._replica_tokens[rid] = (
                     self._replica_tokens.get(rid, 0)
-                    + len(reqs) * len(out_tokens))
+                    + len(reqs) * spec.gen_len)
             sessions = getattr(ctx, "state", None)
             for i, r in enumerate(reqs):
                 if sessions is not None:
@@ -227,16 +250,21 @@ class QoSServer:
                     # are monotonic, so pruning the id one retention window
                     # behind bounds the store in a long-running server.
                     sessions.put(r["id"], {
-                        "generated": len(out_tokens),
-                        "kv_pos": spec.prompt_len + len(out_tokens) - 1,
+                        "generated": spec.gen_len,
+                        "kv_pos": spec.prompt_len + spec.gen_len - 1,
                     })
                     sessions.pop(r["id"] - SESSION_RETENTION, None)
                 emit(
-                    {"request_id": r["id"], "tokens": outs[i].tolist()},
+                    {"request_id": r["id"], "tokens": outs[i].tolist(),
+                     "finite": finite},
                     size_bytes=64,
                     created_at_ms=r["t_arrival"],
                     key=r["id"],
                 )
+
+        def egress_fn(payload, emit, ctx):
+            with self._lock:
+                self.responses.append(payload)
 
         self.jg = JobGraph("qos-serving")
         self.jg.add_vertex(JobVertex("Ingress", 1, is_source=True))
@@ -252,7 +280,8 @@ class QoSServer:
         self.jg.add_vertex(JobVertex(
             "Decode", 1, fn=decode_fn, stateful=elastic,
             chainable=not unchainable_decode))
-        self.jg.add_vertex(JobVertex("Egress", 1, is_sink=True))
+        self.jg.add_vertex(JobVertex("Egress", 1, fn=egress_fn,
+                                     is_sink=True))
         self.jg.add_edge("Ingress", "Prefill", POINTWISE)
         self.jg.add_edge("Prefill", "Decode",
                          ALL_TO_ALL if elastic else POINTWISE)
@@ -299,10 +328,9 @@ class QoSServer:
                     cooldown_ms=2.0 * window_ms)
 
         rng = np.random.default_rng(0)
-        counter = [0]
 
         def make_payload(seq_no: int):
-            counter[0] += 1
+            self._admitted += 1  # the source thread is the only writer
             return (
                 {
                     "id": seq_no,
@@ -328,7 +356,7 @@ class QoSServer:
             measurement_interval_ms=measurement_interval_ms,
             enable_qos=enable_qos,
             enable_chaining=enable_chaining,
-            policy=BufferSizingPolicy(omega_bytes=initial_buffer_bytes * 8),
+            policy=BufferSizingPolicy(omega_bytes=omega_bytes),
         )
         if self.elastic_ctl is not None:
             if autoscaler == "tokens":
@@ -344,18 +372,43 @@ class QoSServer:
             else:
                 self.engine.attach_elastic(self.elastic_ctl)
 
-    # -- jit caches (bucketed batch shapes) ------------------------------------
-    def _prefill_for(self, bsz: int):
-        if bsz not in self._jit_prefill:
-            self._jit_prefill[bsz] = jax.jit(
-                lambda p, b: self.model.prefill(p, b, self.max_len)
-            )
-        return self._jit_prefill[bsz]
+    # -- device steps ----------------------------------------------------------
+    def _prefill_batch(self, reqs: list[dict]) -> dict:
+        """Prefill ``reqs`` padded to their power-of-two bucket; the result
+        is the Prefill->Decode payload."""
+        toks = np.zeros((_bucket(len(reqs)), self.spec.prompt_len), np.int32)
+        for i, r in enumerate(reqs):
+            toks[i] = r["tokens"]
+        tok, finite, cache = self._prefill(self.params, jnp.asarray(toks))
+        return {"cache": cache, "tok": tok, "finite": finite, "reqs": reqs}
 
-    def _decode_for(self, bsz: int):
-        if bsz not in self._jit_decode:
-            self._jit_decode[bsz] = jax.jit(self.model.decode_step)
-        return self._jit_decode[bsz]
+    def _decode_batch(self, st: dict) -> tuple[np.ndarray, bool]:
+        """Greedy-decode a prefilled batch: ([bucket, gen_len] token ids,
+        whether every logit of the batch was finite)."""
+        tok, finite, cache = st["tok"], st["finite"], st["cache"]
+        out_tokens = [tok]
+        for i in range(self.spec.gen_len - 1):
+            tok, finite, cache = self._decode(
+                self.params, cache, tok, self.spec.prompt_len + i, finite)
+            out_tokens.append(tok)
+        outs = np.stack([np.asarray(t) for t in out_tokens], 1)
+        return outs, bool(finite)
+
+    def warmup(self) -> dict[int, float]:
+        """Run the served path once for every batch bucket a run can reach
+        (1 up to the bucket of ``max_batch``), through the same jitted steps
+        and host code the stages call, so that no batch of the run compiles.
+        Returns the host-clock seconds each bucket took, compilation
+        included on a first call."""
+        secs: dict[int, float] = {}
+        b = 1
+        while b <= _bucket(self.max_batch):
+            reqs = [{"tokens": np.zeros(self.spec.prompt_len, np.int32)}] * b
+            t0 = time.perf_counter()
+            self._decode_batch(self._prefill_batch(reqs))
+            secs[b] = time.perf_counter() - t0
+            b *= 2
+        return secs
 
     # -- metrics ---------------------------------------------------------------
     def _kv_tokens_of(self, ex) -> tuple[int, int]:
@@ -452,4 +505,6 @@ class QoSServer:
             scale_log=list(res.scale_log),
             decode_replicas=len(self.engine.rg.tasks_of("Decode")),
             replica_metrics=self.replica_metrics(res.duration_ms),
+            admitted=self._admitted,
+            responses=list(self.responses),
         )

@@ -35,17 +35,10 @@ def _rules() -> dict | None:
 
 
 def _mesh() -> Mesh | None:
+    """The mesh passed to ``use_sharding_rules``; None when none was, or
+    while suspended (shard_map-local tracing)."""
     m = getattr(_state, "mesh", None)
-    if m is False:  # suspended (shard_map-local tracing)
-        return None
-    if m is not None:
-        return m
-    # fall back to ambient mesh from `with mesh:` context
-    env = jax.interpreters.pxla.thread_resources.env
-    phys = getattr(env, "physical_mesh", None)
-    if phys is not None and not phys.empty:
-        return phys
-    return None
+    return None if m is False else m
 
 
 @contextmanager
@@ -55,7 +48,7 @@ def suspend_sharding_rules():
     old_rules = getattr(_state, "rules", None)
     old_mesh = getattr(_state, "mesh", None)
     _state.rules = None
-    _state.mesh = False  # sentinel: also blocks the ambient-mesh fallback
+    _state.mesh = False  # sentinel: suspended, see _mesh()
     try:
         yield
     finally:

@@ -16,6 +16,7 @@ CODE = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from repro.configs import get_config
+    from repro.launch.mesh import auto_mesh
     from repro.launch.partition import batch_shardings, make_rules, param_shardings
     from repro.models import build_model
     from repro.sharding import use_sharding_rules
@@ -40,7 +41,7 @@ CODE = textwrap.dedent("""
         # single device
         l_single = float(jax.jit(model.loss)(params, batch))
         # 2x4 mesh with the production rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rules = make_rules(cfg, mesh, seq_len=S, global_batch=B)
         with mesh, use_sharding_rules(rules, mesh):
             psh = param_shardings(model.logical_axes(), mesh, rules)

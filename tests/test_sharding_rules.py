@@ -65,6 +65,7 @@ def test_multidevice_compile_subprocess():
         sys.path.insert(0, "src")
         import jax
         from repro.configs import get_config
+        from repro.launch.mesh import auto_mesh
         from repro.launch.partition import (batch_shardings, make_rules,
                                             opt_state_shardings,
                                             param_shardings)
@@ -77,7 +78,7 @@ def test_multidevice_compile_subprocess():
         cfg = get_config("qwen3-1.7b", smoke=True).with_(
             num_heads=4, num_kv_heads=4, d_model=64, d_ff=128)
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rules = make_rules(cfg, mesh, seq_len=64, global_batch=8)
         with mesh, use_sharding_rules(rules, mesh):
             ap = model.abstract_params()
