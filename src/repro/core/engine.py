@@ -60,6 +60,11 @@ from .measurement import QoSReporter, Tag, latency_percentile
 from .placement import WorkerPool
 from .routing import StateStore
 from .setup import compute_qos_setup, compute_reporter_setup
+from .tracing import Tracer, now_ns
+
+#: why an output buffer shipped: it filled, it outlived the maximum buffer
+#: lifetime, or it was flushed on purpose (chaining, scale-in, stop)
+FLUSH_CAUSES = ("full", "lifetime", "explicit")
 
 
 @dataclass
@@ -75,6 +80,15 @@ class StreamItem:
     #: item no matter how many receivers.  Never set on receiver-side
     #: copies (their payload may be mutated downstream).
     blob: bytes | None = None
+    #: while tracing: the requests an item a batch stage emitted serves
+    #: (otherwise the item serves the request of its key, core/tracing.py)
+    rids: tuple | None = None
+    #: while tracing: channel id -> when the item entered that channel's
+    #: output buffer, then when the buffer shipped
+    stamps: dict | None = None
+
+    def request_ids(self) -> tuple:
+        return self.rids if self.rids is not None else (self.key,)
 
 
 @dataclass
@@ -172,6 +186,9 @@ class ChannelSender:
         # reporter objects persist per worker id (QoS-scope refreshes mutate
         # them in place), so the per-send dict chase is pure overhead
         self.src_reporter = engine.reporters[src_worker]
+        self.tracer = engine.tracer
+        self._flush_counters = {c: f"engine.flush.{c}:{channel.id}"
+                                for c in FLUSH_CAUSES}
         self.chained = False
         #: set when the src task's worker was crash-killed (core/faults.py):
         #: the process that owned this buffer is gone, so subsequent emits
@@ -211,14 +228,18 @@ class ChannelSender:
                 # above — appending now would strand the item forever
                 eng._count_drop(item.key)
                 return
+            if self.tracer.on:
+                if item.stamps is None:
+                    item.stamps = {}
+                item.stamps[cid] = now_ns()
             full = self.buffer.append(item, item.size_bytes, now)
             if full:
-                self._flush_locked(now)
+                self._flush_locked(now, "full")
 
     def flush(self) -> None:
         with self._lock:
             if not self.buffer.empty:
-                self._flush_locked(self.engine.clock.now())
+                self._flush_locked(self.engine.clock.now(), "explicit")
 
     def flush_if_stale(self, now_ms: float, max_lifetime_ms: float) -> bool:
         """Max-buffer-lifetime flush (§3.5.1 companion): ship an under-filled
@@ -231,14 +252,18 @@ class ChannelSender:
             if (self.buffer.empty or opened is None
                     or now_ms - opened < max_lifetime_ms):
                 return False
-            self._flush_locked(now_ms)
+            self._flush_locked(now_ms, "lifetime")
             return True
 
-    def _flush_locked(self, now: float) -> None:
+    def _flush_locked(self, now: float, cause: str) -> None:
         eng = self.engine
         if now < self.blackhole_until and not eng._stop.is_set():
             return  # partitioned: hold the buffer until the blackhole heals
         items, nbytes, lifetime = self.buffer.take(now)
+        tr = self.tracer
+        tr.count(self._flush_counters[cause])
+        if tr.on:
+            self._trace_flush(items, cause)
         if self.cid in eng.measured_channels:
             self.src_reporter.record_output_buffer_lifetime(
                 self.cid, lifetime, self.buffer.capacity_bytes,
@@ -263,10 +288,24 @@ class ChannelSender:
                     created_at_ms=it.created_at_ms,
                     key=it.key,
                     tag=it.tag,
+                    rids=it.rids,
+                    stamps=it.stamps,
                 ))
             items = shipped
         eng.stats_lock_inc(nbytes, len(items))
         eng.deliver(self.channel, items)
+
+    def _trace_flush(self, items: list[StreamItem], cause: str) -> None:
+        """Each item's time in the buffer (``engine.buffer``); its time in
+        the receiver's inbox starts now (``engine.queue``)."""
+        cid, t = self.cid, now_ns()
+        for it in items:
+            stamps = it.stamps
+            if stamps is None or cid not in stamps:
+                continue  # appended before the tracer was on
+            self.tracer.interval("engine.buffer", stamps[cid], t,
+                                 it.request_ids(), channel=cid, cause=cause)
+            stamps[cid] = t
 
     def try_update_size(self, new_size: int, base_version: int) -> bool:
         with self._lock:
@@ -289,6 +328,9 @@ class TaskExecutor:
         # per-item rg.worker()/reporters[] chase is pure overhead
         self.worker = engine.rg.worker(vertex)
         self.reporter = engine.reporters[self.worker]
+        self.tracer = engine.tracer
+        #: while tracing: the requests of the batch in user code
+        self._batch_rids: tuple | None = None
         jv = engine.jg.vertices[vertex.job_vertex]
         self.fn = jv.fn
         self.batch_mode = jv.batch_fn
@@ -359,6 +401,10 @@ class TaskExecutor:
                 cur.created_at_ms if cur else now),
             key=key if key is not None else (cur.key if cur else 0),
         )
+        if self.tracer.on:
+            item.rids = (self._batch_rids if self.batch_mode
+                         else cur.rids if cur is not None and key is None
+                         else None)
         self.emitted += 1
         routers = eng.rg.routers
         for dst_jv, senders in self.senders.items():
@@ -442,6 +488,8 @@ class TaskExecutor:
             self._pending_task_sample = now
         if self.is_sink:
             eng.record_sink_latency(now - item.created_at_ms, item.key)
+        if self.tracer.on:
+            self._trace_queue([item], in_channel_id)
         t0 = time.perf_counter()
         self._current_item = item
         try:
@@ -524,6 +572,10 @@ class TaskExecutor:
             and rep.should_sample_task(vid)
         ):
             self._pending_task_sample = now
+        if self.tracer.on:
+            self._trace_queue(items, in_channel_id)
+            self._batch_rids = tuple(
+                r for it in items for r in it.request_ids())
         t0 = time.perf_counter()
         self._current_item = items[0] if items else None
         try:
@@ -536,6 +588,17 @@ class TaskExecutor:
             dt = (time.perf_counter() - t0) * 1e3
             self._busy_ms += dt
             self.busy_ms_total += dt
+
+    def _trace_queue(self, items: list[StreamItem], in_channel_id: str) -> None:
+        """Each item's time from its buffer's shipping to the start of this
+        task's user code (``engine.queue``)."""
+        t = now_ns()
+        for it in items:
+            stamps = it.stamps
+            if stamps is not None and in_channel_id in stamps:
+                self.tracer.interval(
+                    "engine.queue", stamps.pop(in_channel_id), t,
+                    it.request_ids(), channel=in_channel_id)
 
     # -- thread body ------------------------------------------------------------------
     def run(self) -> None:
@@ -697,6 +760,10 @@ class StreamEngine(RuntimeRewirer):
             self.measured_channels |= r.interested_channels()
             self.measured_tasks |= r.interested_tasks()
 
+        #: request-scoped spans and counters (core/tracing.py), off until
+        #: ``tracer.enable()``
+        self.tracer = Tracer()
+
         # runtime structures
         self.executors: dict[RuntimeVertex, TaskExecutor] = {
             v: TaskExecutor(v, self) for v in self.rg.vertices
@@ -818,6 +885,9 @@ class StreamEngine(RuntimeRewirer):
     # -- source pacing ------------------------------------------------------------------
     def _source_body(self, v: RuntimeVertex, spec: SourceSpec) -> None:
         ex = self.executors[v]
+        tr = self.tracer
+        #: while tracing, the span from one emission to the next
+        wait = None
         next_t = time.monotonic()
         while not self._stop.is_set() and not ex.crashed:
             ex.paused.wait()
@@ -834,7 +904,14 @@ class StreamEngine(RuntimeRewirer):
             if now < next_t:
                 time.sleep(min(next_t - now, 0.05))
                 continue
+            if wait is not None:
+                wait.end()
+                wait = None
             seq = ex.src_seq
+            # the item's due time on the schedule (next_t before it advances)
+            emission = (tr.span("engine.source.emit", (spec.key_of(seq),),
+                                seq=seq, due_ns=int(next_t * 1e9)).start()
+                        if tr.on else None)
             rate = spec.rate_at(self.clock.now() - self._t0)
             next_t += 1.0 / max(rate, 1e-9)
             payload, size = spec.make_payload(seq)
@@ -856,73 +933,96 @@ class StreamEngine(RuntimeRewirer):
                 ex._busy_ms += dt
                 ex.busy_ms_total += dt
             ex.src_seq = seq + 1
+            if emission is not None:
+                emission.end()
+                wait = tr.span("engine.source.wait").start()
+        if wait is not None:
+            wait.end()
 
     # -- QoS control loop ------------------------------------------------------------------
     def _control_body(self) -> None:
+        tr = self.tracer
         while not self._stop.is_set():
             time.sleep(self.interval_ms / 1e3 / 4)
-            # max-buffer-lifetime sweep: ship under-filled buffers that have
-            # been open too long (runs regardless of enable_qos — it is a
-            # liveness guarantee, not a countermeasure)
-            if self.max_buffer_lifetime_ms is not None:
-                now = self.clock.now()
-                for s in list(self.senders.values()):
-                    s.flush_if_stale(now, self.max_buffer_lifetime_ms)
-            # crash detection -> recovery (core/faults.py): the monitor's
-            # clock is the engine clock, so detection latency is wall time;
-            # periodic checkpoints ride the same tick
-            if self._monitor is not None:
-                self._liveness_tick(self.clock.now())
-            self._maybe_checkpoint(self.clock.now())
-            # cpu utilization sampling feeds the chaining precondition
-            # (snapshot: elastic re-wiring swaps these dicts live; a dead
-            # worker's reporter is gone — skip, don't resurrect)
-            measured = self.measured_tasks
-            for v, ex in list(self.executors.items()):
-                if v.id in measured and not ex.retired:
-                    rep = self.reporters.get(self.rg.worker(v))
-                    if rep is not None:
-                        rep.record_task_cpu(
-                            v.id, ex.cpu_utilization(), ex.chained
-                        )
-            # reporters -> managers
-            managers = self.managers
-            for rep in list(self.reporters.values()):
-                for mgr_id, report in rep.maybe_flush():
-                    mgr = managers.get(mgr_id)
-                    if mgr is not None:
-                        mgr.receive_report(report)
-            # predictive QoS: feed the rate estimators on the control tick
-            # (no-op with proactive=None — _estimator_tick guards)
-            if self.proactive is not None:
-                self._estimator_tick(self.clock.now())
-            # attached elastic controllers sample on their own cadence
-            for st in list(self._elastic):
-                if self.clock.now() >= st.get("next_ms", 0.0):
-                    st["next_ms"] = self.clock.now() + st["period_ms"]
-                    self.elastic_check(st)
-            # time-to-SLO-recovery: first tick after a crash where every
-            # latency constraint is evaluable and satisfied again
-            if self._slo_pending_since is not None:
-                self._slo_recovery_check(self.clock.now())
-            if not self.enable_qos:
-                continue
-            # managers act
-            for mgr in list(self.managers.values()):
-                for action in mgr.check():
-                    self._route_action(action)
+            if tr.on:
+                with tr.span("engine.qos_tick") as tick:
+                    tick.set(**self._control_tick())
+            else:
+                self._control_tick()
 
-    def _route_action(self, action: Action) -> None:
+    def _control_tick(self) -> dict[str, int]:
+        """One iteration of the QoS control loop: the reports it handed to
+        the managers and the actions they issued, by kind."""
+        done: dict[str, int] = {"reports": 0}
+        # max-buffer-lifetime sweep: ship under-filled buffers that have
+        # been open too long (runs regardless of enable_qos — it is a
+        # liveness guarantee, not a countermeasure)
+        if self.max_buffer_lifetime_ms is not None:
+            now = self.clock.now()
+            for s in list(self.senders.values()):
+                s.flush_if_stale(now, self.max_buffer_lifetime_ms)
+        # crash detection -> recovery (core/faults.py): the monitor's
+        # clock is the engine clock, so detection latency is wall time;
+        # periodic checkpoints ride the same tick
+        if self._monitor is not None:
+            self._liveness_tick(self.clock.now())
+        self._maybe_checkpoint(self.clock.now())
+        # cpu utilization sampling feeds the chaining precondition
+        # (snapshot: elastic re-wiring swaps these dicts live; a dead
+        # worker's reporter is gone — skip, don't resurrect)
+        measured = self.measured_tasks
+        for v, ex in list(self.executors.items()):
+            if v.id in measured and not ex.retired:
+                rep = self.reporters.get(self.rg.worker(v))
+                if rep is not None:
+                    rep.record_task_cpu(
+                        v.id, ex.cpu_utilization(), ex.chained
+                    )
+        # reporters -> managers
+        managers = self.managers
+        for rep in list(self.reporters.values()):
+            for mgr_id, report in rep.maybe_flush():
+                mgr = managers.get(mgr_id)
+                if mgr is not None:
+                    mgr.receive_report(report)
+                    done["reports"] += 1
+        # predictive QoS: feed the rate estimators on the control tick
+        # (no-op with proactive=None — _estimator_tick guards)
+        if self.proactive is not None:
+            self._estimator_tick(self.clock.now())
+        # attached elastic controllers sample on their own cadence
+        for st in list(self._elastic):
+            if self.clock.now() >= st.get("next_ms", 0.0):
+                st["next_ms"] = self.clock.now() + st["period_ms"]
+                self.elastic_check(st)
+        # time-to-SLO-recovery: first tick after a crash where every
+        # latency constraint is evaluable and satisfied again
+        if self._slo_pending_since is not None:
+            self._slo_recovery_check(self.clock.now())
+        if not self.enable_qos:
+            return done
+        # managers act
+        for mgr in list(self.managers.values()):
+            for action in mgr.check():
+                kind = self._route_action(action)
+                done[kind] = done.get(kind, 0) + 1
+        return done
+
+    def _route_action(self, action: Action) -> str:
+        """Apply ``action``; counts it under ``qos.<kind>`` and returns the
+        kind."""
+        kind = type(action).__name__
         if isinstance(action, BufferSizeUpdate):
             sender = self.senders.get(action.channel_id)
-            if sender is not None:
-                sender.try_update_size(
-                    action.new_size_bytes, action.base_version
-                )
+            applied = sender is not None and sender.try_update_size(
+                action.new_size_bytes, action.base_version)
+            kind = "buffer_resize" if applied else "buffer_resize_refused"
         elif isinstance(action, ChainRequest):
+            kind = "chain"
             if self.enable_chaining:
                 self.apply_chain(action)
         elif isinstance(action, ScaleRequest):
+            kind = "scale"
             try:
                 if action.to_parallelism < action.from_parallelism:
                     # proactive give-back: the manager's forecast path may
@@ -938,7 +1038,10 @@ class StreamEngine(RuntimeRewirer):
                 # inapplicable/aborted, never fatal to the control loop
                 pass
         elif isinstance(action, GiveUp):
+            kind = "give_up"
             self._give_ups.append(action)
+        self.tracer.count(f"qos.{kind}")
+        return kind
 
     # -- fault injection (core/faults.py; docs/robustness.md) ----------------------------
     def _injector_body(self) -> None:

@@ -38,6 +38,7 @@ default request-count telemetry.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -60,12 +61,15 @@ from ..core import (
     ThroughputConstraint,
 )
 from ..core.buffers import BufferSizingPolicy
+from ..core.measurement import latency_percentile
 from ..models import Model
 
 
 #: completed-session records older than this many request ids are pruned
 #: from the Decode replicas' keyed state (ids are a monotonic sequence).
 SESSION_RETENTION = 4096
+#: what an instrumented point enters while the tracer is off
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclass
@@ -128,8 +132,8 @@ class ServingResult:
         return sum(tail) / len(tail)
 
     def p(self, q: float) -> float:
-        xs = sorted(self.latencies_ms)
-        return xs[min(len(xs) - 1, int(q * len(xs)))] if xs else float("nan")
+        """The shared nearest-rank percentile (core/measurement.py)."""
+        return latency_percentile(self.latencies_ms, q)
 
     @property
     def throughput_rps(self) -> float:
@@ -139,6 +143,11 @@ class ServingResult:
     def mean_batch(self) -> float:
         bs = self.batch_sizes
         return sum(bs) / len(bs) if bs else float("nan")
+
+
+def _ids(reqs: list[dict]) -> tuple:
+    """The request ids of a batch (warm-up batches have none)."""
+    return tuple(r["id"] for r in reqs if "id" in r)
 
 
 def _bucket(n: int) -> int:
@@ -213,7 +222,6 @@ class QoSServer:
         self._prefill, self._decode = serving_steps(model, self.max_len)
         self.batch_sizes: list[int] = []
         self.responses: list[dict] = []
-        self._admitted = 0
         #: per-replica generated-token counters (replica id -> tokens);
         #: sampled with the KV-cache occupancy gauges into replica_metrics
         self._replica_tokens: dict[str, int] = {}
@@ -263,8 +271,12 @@ class QoSServer:
                 )
 
         def egress_fn(payload, emit, ctx):
-            with self._lock:
-                self.responses.append(payload)
+            tr = self.tracer
+            with (tr.span("serving.egress", (payload["request_id"],))
+                  if tr.on else _NO_SPAN):
+                with self._lock:
+                    self.responses.append(payload)
+            tr.count("serving.answers")
 
         self.jg = JobGraph("qos-serving")
         self.jg.add_vertex(JobVertex("Ingress", 1, is_source=True))
@@ -330,7 +342,7 @@ class QoSServer:
         rng = np.random.default_rng(0)
 
         def make_payload(seq_no: int):
-            self._admitted += 1  # the source thread is the only writer
+            self.tracer.count("serving.admitted")
             return (
                 {
                     "id": seq_no,
@@ -358,6 +370,8 @@ class QoSServer:
             enable_chaining=enable_chaining,
             policy=BufferSizingPolicy(omega_bytes=omega_bytes),
         )
+        #: the engine's request-scoped spans and counters (core/tracing.py)
+        self.tracer = self.engine.tracer
         if self.elastic_ctl is not None:
             if autoscaler == "tokens":
                 # token-aware autoscaling: replace the default emitted/busy
@@ -376,23 +390,42 @@ class QoSServer:
     def _prefill_batch(self, reqs: list[dict]) -> dict:
         """Prefill ``reqs`` padded to their power-of-two bucket; the result
         is the Prefill->Decode payload."""
-        toks = np.zeros((_bucket(len(reqs)), self.spec.prompt_len), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i] = r["tokens"]
-        tok, finite, cache = self._prefill(self.params, jnp.asarray(toks))
+        rows, bucket = len(reqs), _bucket(len(reqs))
+        tr = self.tracer
+        tr.count(f"serving.rows.real:{bucket}", rows)
+        tr.count(f"serving.rows.padded:{bucket}", bucket - rows)
+        with (tr.span("serving.prefill_batch", _ids(reqs), rows=rows,
+                      bucket=bucket) if tr.on else _NO_SPAN):
+            toks = np.zeros((bucket, self.spec.prompt_len), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i] = r["tokens"]
+            tok, finite, cache = self._prefill(self.params, jnp.asarray(toks))
         return {"cache": cache, "tok": tok, "finite": finite, "reqs": reqs}
 
     def _decode_batch(self, st: dict) -> tuple[np.ndarray, bool]:
         """Greedy-decode a prefilled batch: ([bucket, gen_len] token ids,
         whether every logit of the batch was finite)."""
         tok, finite, cache = st["tok"], st["finite"], st["cache"]
+        reqs, steps = st["reqs"], self.spec.gen_len - 1
+        tr = self.tracer
+        tr.count("serving.decode_steps", steps)
+        on = tr.on
+        rids = _ids(reqs) if on else ()
         out_tokens = [tok]
-        for i in range(self.spec.gen_len - 1):
-            tok, finite, cache = self._decode(
-                self.params, cache, tok, self.spec.prompt_len + i, finite)
-            out_tokens.append(tok)
-        outs = np.stack([np.asarray(t) for t in out_tokens], 1)
-        return outs, bool(finite)
+        with (tr.span("serving.decode_batch", rids, rows=len(reqs),
+                      bucket=_bucket(len(reqs))) if on else _NO_SPAN):
+            for i in range(steps):
+                with (tr.span("serving.decode_step", rids, step=i)
+                      if on else _NO_SPAN):
+                    tok, finite, cache = self._decode(
+                        self.params, cache, tok, self.spec.prompt_len + i,
+                        finite)
+                out_tokens.append(tok)
+            # waits for the last step: the device's time shows here
+            with tr.span("serving.fetch", rids) if on else _NO_SPAN:
+                outs = np.stack([np.asarray(t) for t in out_tokens], 1)
+                finite = bool(finite)
+        return outs, finite
 
     def warmup(self) -> dict[int, float]:
         """Run the served path once for every batch bucket a run can reach
@@ -505,6 +538,6 @@ class QoSServer:
             scale_log=list(res.scale_log),
             decode_replicas=len(self.engine.rg.tasks_of("Decode")),
             replica_metrics=self.replica_metrics(res.duration_ms),
-            admitted=self._admitted,
+            admitted=self.tracer.counters.get("serving.admitted", 0),
             responses=list(self.responses),
         )
