@@ -166,7 +166,12 @@ def serving_steps(model: Model, max_len: int):
     ``prefill(params, tokens[B, P]) -> (token[B], finite, cache)``
     ``decode(params, cache, token[B], pos, finite) -> (token[B], finite,
     cache)``: ``pos`` is the position written this step (a scalar, the same
-    for every row), ``finite`` accumulates over the steps."""
+    for every row), ``finite`` accumulates over the steps.
+
+    ``decode`` takes its cache donated: the output cache aliases the input,
+    so the layer loop writes the new token's slot in place in HBM instead of
+    staging the whole cache and writing a second one.  The caller's input
+    cache is deleted by the call and must not be read again."""
 
     def prefill(params, tokens):
         logits, cache = model.prefill(params, {"tokens": tokens}, max_len)
@@ -179,7 +184,7 @@ def serving_steps(model: Model, max_len: int):
         return (jnp.argmax(logits, -1).astype(jnp.int32),
                 finite & jnp.isfinite(logits).all(), cache)
 
-    return jax.jit(prefill), jax.jit(decode)
+    return jax.jit(prefill), jax.jit(decode, donate_argnums=(1,))
 
 
 class QoSServer:
@@ -404,27 +409,37 @@ class QoSServer:
 
     def _decode_batch(self, st: dict) -> tuple[np.ndarray, bool]:
         """Greedy-decode a prefilled batch: ([bucket, gen_len] token ids,
-        whether every logit of the batch was finite)."""
-        tok, finite, cache = st["tok"], st["finite"], st["cache"]
+        whether every logit of the batch was finite).
+
+        Each step consumes the cache it is given (``serving_steps`` donates
+        it), so the cache leaves the payload here and no later reader of
+        ``st`` finds a deleted array."""
+        tok, finite, cache = st["tok"], st["finite"], st.pop("cache")
         reqs, steps = st["reqs"], self.spec.gen_len - 1
         tr = self.tracer
         tr.count("serving.decode_steps", steps)
         on = tr.on
         rids = _ids(reqs) if on else ()
         out_tokens = [tok]
+        donated = 0
         with (tr.span("serving.decode_batch", rids, rows=len(reqs),
                       bucket=_bucket(len(reqs))) if on else _NO_SPAN):
             for i in range(steps):
+                leaf = jax.tree.leaves(cache)[0]
                 with (tr.span("serving.decode_step", rids, step=i)
                       if on else _NO_SPAN):
                     tok, finite, cache = self._decode(
                         self.params, cache, tok, self.spec.prompt_len + i,
                         finite)
+                # a host-side flag, set at dispatch: the runtime took the
+                # input cache's buffers for the output
+                donated += leaf.is_deleted()
                 out_tokens.append(tok)
             # waits for the last step: the device's time shows here
             with tr.span("serving.fetch", rids) if on else _NO_SPAN:
                 outs = np.stack([np.asarray(t) for t in out_tokens], 1)
                 finite = bool(finite)
+        tr.count("serving.decode_steps.donated", donated)
         return outs, finite
 
     def warmup(self) -> dict[int, float]:

@@ -1,10 +1,14 @@
 """QoS serving plane: batch-mode tasks + adaptive batch sizing."""
+from functools import partial
+
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core import SPREAD, StreamEngine, WorkerPool
 from repro.models import build_model
-from repro.serving import QoSServer, RequestSpec
+from repro.serving import QoSServer, RequestSpec, qos_server
 
 
 @pytest.fixture(scope="module")
@@ -150,3 +154,104 @@ def test_mid_run_spawned_replica_true_throughput(model_and_params):
     whole_run_rate = g["tokens_generated"] / (res.duration_ms / 1e3)
     if g["tokens_generated"]:
         assert g["token_throughput_per_s"] > whole_run_rate * 1.2
+
+
+# -- the decode step's donated cache ---------------------------------------------
+def _one_at_a_time(m, params, cfg, **kw) -> QoSServer:
+    """A server that ships every request alone: each answer depends on its
+    own prompt only, whatever the timing."""
+    spec = RequestSpec(rate_per_s=20.0, prompt_len=8, gen_len=4,
+                       vocab=cfg.vocab_size)
+    return QoSServer(m, params, spec, latency_limit_ms=500.0,
+                     enable_qos=False, initial_buffer_bytes=64, **kw)
+
+
+def _answers(srv: QoSServer, ms: float) -> dict[int, list[int]]:
+    srv.warmup()
+    res = srv.run(ms)
+    assert res.responses
+    return {r["request_id"]: r["tokens"] for r in res.responses}
+
+
+def test_decode_step_consumes_its_input_cache(model_and_params):
+    m, params, cfg = model_and_params
+    srv = _one_at_a_time(m, params, cfg)
+    st = srv._prefill_batch([{"tokens": np.arange(8, dtype=np.int32)}])
+    cache = st["cache"]
+    _, _, out = srv._decode(params, cache, st["tok"], 8, st["finite"])
+    assert all(x.is_deleted() for x in jax.tree.leaves(cache))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(out))
+    # the batch path drops the payload's reference to the donated cache
+    st = srv._prefill_batch([{"tokens": np.arange(8, dtype=np.int32)}])
+    srv._decode_batch(st)
+    assert "cache" not in st
+
+
+def test_donated_decode_serves_the_tokens_of_an_undonated_twin(
+        model_and_params):
+    """A run through the donating step answers token for token as a run
+    through the same step jitted without donation; the counter tells the
+    two apart."""
+    m, params, cfg = model_and_params
+    srv = _one_at_a_time(m, params, cfg)
+    donated = _answers(srv, 1_500.0)
+    twin = _one_at_a_time(m, params, cfg)
+    twin._decode = jax.jit(twin._decode.__wrapped__)
+    undonated = _answers(twin, 1_500.0)
+    common = donated.keys() & undonated.keys()
+    assert len(common) >= 5
+    assert {i: donated[i] for i in common} == {i: undonated[i]
+                                               for i in common}
+    c = srv.tracer.counters
+    assert c["serving.decode_steps"] > 0
+    assert c["serving.decode_steps.donated"] == c["serving.decode_steps"]
+    assert twin.tracer.counters["serving.decode_steps.donated"] == 0
+
+
+def test_donated_cache_crosses_workers_pickled(model_and_params,
+                                              monkeypatch):
+    """With Prefill and Decode on different workers the Prefill->Decode
+    payload, the cache with it, is pickled at the flush; Decode then donates
+    its own copy, and every admitted request is answered once."""
+    m, params, cfg = model_and_params
+    # the server's modulo fleet puts every stage on worker 0; two-slot
+    # workers filled least-loaded first put Ingress and Decode on worker 0,
+    # Prefill and Egress on worker 1
+    monkeypatch.setattr(qos_server, "StreamEngine", partial(
+        StreamEngine, pool=WorkerPool(2, policy=SPREAD, slots_per_worker=2)))
+    srv = _one_at_a_time(m, params, cfg, num_workers=2)
+    rg = srv.engine.rg
+    prefill, = rg.tasks_of("Prefill")
+    decode, = rg.tasks_of("Decode")
+    assert rg.worker(prefill) != rg.worker(decode)
+    channel, = rg.channels_of("Prefill", "Decode")
+    assert srv.engine.senders[channel.id].cross_worker
+    srv.warmup()
+    res = srv.run(1_500.0)
+    ids = [r["request_id"] for r in res.responses]
+    assert res.admitted > 0
+    assert sorted(ids) == list(range(res.admitted))
+    c = srv.tracer.counters
+    assert c["serving.decode_steps.donated"] == c["serving.decode_steps"]
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "mixtral-8x7b",
+                                  "mamba2-130m", "zamba2-7b"])
+def test_every_served_family_donates_its_cache(arch):
+    """One architecture per family the server runs (dense, moe, ssm,
+    hybrid): each step's output cache takes its input's buffers, with no
+    warning that a donated buffer went unused."""
+    import warnings
+
+    cfg = get_config(arch, smoke=True)
+    m = build_model(cfg)
+    spec = RequestSpec(prompt_len=8, gen_len=4, vocab=cfg.vocab_size)
+    srv = QoSServer(m, m.init_params(jax.random.PRNGKey(0)), spec,
+                    enable_qos=False)
+    st = srv._prefill_batch([{"tokens": np.arange(3, 11, dtype=np.int32)}])
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers")
+        _, finite = srv._decode_batch(st)
+    assert finite
+    c = srv.tracer.counters
+    assert c["serving.decode_steps.donated"] == c["serving.decode_steps"] == 3

@@ -6,6 +6,8 @@ program that does not fit the chip's memory, fails here at no chip time.
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and test workers import every
 test file.  All compiles against it stay in this one file."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -75,16 +77,58 @@ def test_prefill_compiles_and_fits(served, one_chip):
     assert _bytes_on_chip(compiled) < HBM_BYTES
 
 
-def test_decode_step_compiles_and_fits(served, one_chip):
-    """Without donation the step writes a second cache: its output bytes
-    count that copy."""
+@pytest.fixture(scope="module")
+def decode_compiled(served, one_chip):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     cache = _on(one_chip, served["model"].init_cache_schema(
         BATCH, served["spec"].max_len))
     compiled = served["decode"].lower(
         served["params"], cache, sds((BATCH,), jnp.int32),
         sds((), jnp.int32), sds((), jnp.bool_)).compile()
-    assert _bytes_on_chip(compiled) < HBM_BYTES
+    return compiled, cache
+
+
+def test_decode_step_compiles_and_fits(decode_compiled):
+    """The step takes its cache donated: the output cache aliases the input,
+    so the output bytes that alias count once."""
+    compiled, _ = decode_compiled
+    m = compiled.memory_analysis()
+    assert _bytes_on_chip(compiled) - m.alias_size_in_bytes < HBM_BYTES
+
+
+def test_decode_step_aliases_its_whole_cache(decode_compiled):
+    """Every byte of the cache, as the chip lays it out, is an output that
+    aliases its donated input."""
+    compiled, cache = decode_compiled
+    # the cache's bytes on the chip (tiling pads the position table)
+    laid_out = jax.jit(lambda c: jnp.int32(0), keep_unused=True).lower(
+        cache).compile().memory_analysis().argument_size_in_bytes
+    assert laid_out >= sum(x.size * x.dtype.itemsize
+                           for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes == laid_out
+
+
+def _hlo_shape(x) -> str:
+    """The HLO spelling of an array's shape, as in ``bf16[28,16,584,8,128]``."""
+    names = {"bfloat16": "bf16", "int32": "s32", "float32": "f32"}
+    return f"{names[str(x.dtype)]}[{','.join(map(str, x.shape))}]"
+
+
+def test_decode_step_stages_no_cache(decode_compiled):
+    """No async slice or copy takes a cache parameter, or moves a buffer of
+    the stacked K or V cache's shape: nothing stages the cache into VMEM
+    before the layer loop or copies it out to a fresh output after it.  (The
+    position table, 37 KB, may still sit in VMEM through the loop.)"""
+    compiled, cache = decode_compiled
+    hlo = compiled.as_text()
+    params = set(re.findall(r"%(cache\S*) = \S+ parameter\(", hlo))
+    assert len(params) == len(jax.tree.leaves(cache)), params
+    shapes = {_hlo_shape(cache["k"]), _hlo_shape(cache["v"])}
+    staged = [line.strip()[:200] for line in hlo.splitlines()
+              if re.search(r"= .*\b(slice|copy)-start\(", line)
+              and (any(f"%{p})" in line or f"%{p}," in line for p in params)
+                   or any(s in line for s in shapes))]
+    assert not staged, staged
 
 
 def test_rmsnorm_kernel_compiles(one_chip):
