@@ -16,9 +16,7 @@ import math
 
 import numpy as np
 
-from . import reference
 from .harness import Run, prompts_for
-from .weights import make_weights
 
 #: served tokens the value check covers in each run
 CHECK_TOKENS = 512
@@ -52,21 +50,22 @@ def sample(run: Run) -> list[int]:
     return sorted(int(i) for i in rng.choice(ids, size=k, replace=False))
 
 
-def gaps(hf: dict, seed: int, prompt_len: int, prompts: np.ndarray,
+def gaps(arch, hf: dict, seed: int, prompt_len: int, prompts: np.ndarray,
          served: np.ndarray, *, control: bool = False) -> np.ndarray:
     """Per served position, the reference's best logit minus its logit of the
     token judged: the served token, or with ``control`` the token that the
-    reference computed in fp8 puts first.  ``prompts [B, P]``,
+    reference computed in fp8 puts first.  ``arch`` is the configuration's
+    architecture module (``harness.load_arch``); ``prompts [B, P]``,
     ``served [B, G]``; returns ``[B, G]``."""
     import jax.numpy as jnp
 
-    w = make_weights(hf, seed)
+    w = arch.make_weights(hf, seed)
     tokens = np.concatenate([prompts, served[:, :-1]], axis=1)
     first, count = prompt_len - 1, served.shape[1]
-    ref = reference.logits(hf, w, tokens, first, count)
+    ref = arch.logits(hf, w, tokens, first, count)
     if control:
         judged = jnp.argmax(
-            reference.logits(hf, w, tokens, first, count, quant="fp8"), -1)
+            arch.logits(hf, w, tokens, first, count, quant="fp8"), -1)
     else:
         judged = jnp.asarray(served)
     best = ref.max(-1)
@@ -92,8 +91,8 @@ def widest_gap(run: Run, *, control: bool = False) -> float | None:
     if not ids:
         return None
     prompts, served = sample_arrays(run, ids)
-    g = gaps(run.cell.config, run.seed, run.cell.traffic["prompt_len"],
-             prompts, served, control=control)
+    g = gaps(run.cell.arch, run.cell.config, run.seed,
+             run.cell.traffic["prompt_len"], prompts, served, control=control)
     return float(g.max())
 
 

@@ -19,6 +19,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 
 import numpy as np
 
@@ -26,6 +27,8 @@ from . import ROOT
 from .arrivals import Schedule, rate_fn_of
 
 BENCH = ROOT / "bench"
+#: one module per ``model_type``: the code that depends on the architecture
+ARCHS = BENCH / "archs"
 #: traces are written here, at a fixed path inside the checkout
 TRACE_DIR = ROOT / ".bench" / "trace"
 #: the cell's own traffic runs this many constraint windows (``window_ms``)
@@ -60,10 +63,29 @@ class Cell:
     end_to_end: list[str]
     per_layer: list[str]
     units: dict[str, str]
+    #: the configuration's architecture module (``load_arch``)
+    arch: ModuleType
 
 
-def load_cell(name: str, bench: dict | None = None) -> Cell:
-    """The cell ``name`` of ``BENCHMARK.json`` with its files read."""
+def load_arch(model_type: str, archs: Path = ARCHS) -> ModuleType:
+    """The module ``<archs>/<model_type>.py``.  It provides the program's
+    ``model_config(hf)`` and ``make_params(hf, cfg, seed)``, the reference's
+    ``make_weights(hf, seed)`` and ``logits(hf, w, tokens, first, count, *,
+    quant=None)``, the counts ``prefill_flops(hf, rows, prompt_len, run)``,
+    ``decode_flops(hf, rows, pos, run)`` and ``decode_bytes(hf, rows, pos,
+    run)``, and ``tiny(hf)``, the configuration at the CPU's size."""
+    known = sorted(p.stem for p in archs.glob("*.py"))
+    if model_type not in known:
+        raise KeyError(f"no architecture module for model_type "
+                       f"{model_type!r} in {archs}; known: {known}")
+    return _load_module("bench.archs." + model_type.replace(".", "_"),
+                        archs / f"{model_type}.py")
+
+
+def load_cell(name: str, bench: dict | None = None,
+              archs: Path = ARCHS) -> Cell:
+    """The cell ``name`` of ``BENCHMARK.json`` with its files read and its
+    architecture module loaded from ``archs``."""
     bench = bench if bench is not None else load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -74,15 +96,17 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
     def listed(m: dict) -> bool:
         return name in m.get("workloads", [name])
 
+    config = load_json(ROOT / conf["file"])
     return Cell(
         name=name,
         chips=w["chips"],
-        config=load_json(ROOT / conf["file"]),
+        config=config,
         traffic=load_json(BENCH / "traffic" / f"{w['traffic']}.json"),
         end_to_end=[m["name"] for m in bench["end_to_end"] if listed(m)],
         per_layer=[m["name"] for m in bench["per_layer"] if listed(m)],
         units={m["name"]: m["unit"]
                for m in bench["end_to_end"] + bench["per_layer"]},
+        arch=load_arch(config["model_type"], archs),
     )
 
 
@@ -249,6 +273,10 @@ class Run:
     #: the trace's reduction (``trace_reduce.Reduction``), when traced
     trace: object | None = None
     peaks: dict = field(default_factory=dict)
+    #: copies of the program's counters (``engine.tracer.counters``) when the
+    #: window opened and when the drain ended; None where it has none
+    counters_open: dict[str, int] | None = None
+    counters_end: dict[str, int] | None = None
 
     def answered(self) -> list[Request]:
         return [self.requests[i] for i in self.measured
@@ -285,8 +313,8 @@ def serve(cell: Cell, seed: int, seconds: float, *, t_start: float,
     if rate_per_s is not None:
         traffic["rate_per_s"] = rate_per_s
     server = dict(hf["server"])
-    cfg = system.model_config(hf)
-    params = system.make_params(hf, cfg, seed)
+    cfg = cell.arch.model_config(hf)
+    params = cell.arch.make_params(hf, cfg, seed)
     srv = system.make_server(cfg, params, traffic, server)
     del params
     rate_fn = rate_fn_of(traffic)
@@ -310,6 +338,11 @@ def serve(cell: Cell, seed: int, seconds: float, *, t_start: float,
     jax.monitoring.register_event_duration_secs_listener(on_event)
     trace_path = None
     engine = srv.engine
+    tracer = getattr(engine, "tracer", None)
+
+    def counters() -> dict[str, int] | None:
+        return None if tracer is None else dict(tracer.counters)
+
     try:
         engine.start()
         if not rec.started.wait(timeout=30.0):
@@ -317,6 +350,7 @@ def serve(cell: Cell, seed: int, seconds: float, *, t_start: float,
         open_t = rec.anchor + warm_in_s
         close_t = open_t + seconds
         _sleep_until(open_t)
+        counters_open = counters()
         setup_s = open_t - t_start
         if trace:
             trace_path = TRACE_DIR / cell.name
@@ -340,6 +374,7 @@ def serve(cell: Cell, seed: int, seconds: float, *, t_start: float,
                and time.monotonic() < close_t + DRAIN_LIMIT_S):
             time.sleep(0.02)
         drained_t = time.monotonic()
+        counters_end = counters()
     finally:
         engine.stop()
         for th in list(engine._threads):
@@ -358,6 +393,7 @@ def serve(cell: Cell, seed: int, seconds: float, *, t_start: float,
         window_compiles=[e for t, e in compiles if open_t <= t <= drained_t],
         memory_peak_bytes=stats.get("peak_bytes_in_use"),
         trace_path=trace_path,
+        counters_open=counters_open, counters_end=counters_end,
     )
 
 
@@ -368,14 +404,17 @@ def _clear(path: Path) -> None:
     path.mkdir(parents=True, exist_ok=True)
 
 
-def load_metric(name: str):
-    """The reader of metric ``name``: ``bench/metrics/<name>.py``'s ``read``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench.metrics." + name.replace(".", "_"), path)
+def _load_module(name: str, path: Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric(name: str):
+    """The reader of metric ``name``: ``bench/metrics/<name>.py``'s ``read``."""
+    return _load_module("bench.metrics." + name.replace(".", "_"),
+                        BENCH / "metrics" / f"{name}.py").read
 
 
 def read_metrics(run: Run, names: list[str]) -> dict:

@@ -43,14 +43,15 @@ def decode_roofline_pct(run):
     device time they took."""
     if run.trace is None:
         return None
-    hf, prompt_len = run.cell.config, run.cell.traffic["prompt_len"]
+    arch, hf = run.cell.arch, run.cell.config
+    prompt_len = run.cell.traffic["prompt_len"]
     least = took = 0.0
     for x in run.trace.program("decode"):
         if x.rows is None:
             continue
         pos = prompt_len + x.index
-        least += flops.min_seconds(flops.decode_flops(hf, x.rows, pos),
-                                   flops.decode_bytes(hf, x.rows, pos),
+        least += flops.min_seconds(arch.decode_flops(hf, x.rows, pos, run),
+                                   arch.decode_bytes(hf, x.rows, pos, run),
                                    run.peaks)
         took += x.dur_ns / 1e9
     return 100.0 * least / took if took else None
@@ -60,12 +61,12 @@ def mfu_pct(run):
     """Useful model operations of the batches that finished in the window
     (prefill and every decode step of their real rows) over the window's
     seconds times the chip's peak, in %."""
-    hf, tr = run.cell.config, run.cell.traffic
+    arch, hf, tr = run.cell.arch, run.cell.config, run.cell.traffic
     p, g = tr["prompt_len"], tr["gen_len"]
     total = 0.0
     for b in run.batches_in_window():
-        total += flops.prefill_flops(hf, b.rows, p)
-        total += sum(flops.decode_flops(hf, b.rows, p + i)
+        total += arch.prefill_flops(hf, b.rows, p, run)
+        total += sum(arch.decode_flops(hf, b.rows, p + i, run)
                      for i in range(g - 1))
     if not total:
         return None

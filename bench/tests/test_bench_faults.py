@@ -1,21 +1,27 @@
 """The rest of a run, past the harness's look for a chip, with the timed
-path broken underneath: each fault has to turn ``correct`` false."""
+path broken underneath, in every configuration of ``BENCHMARK.json``: each
+fault has to turn ``correct`` false."""
 import numpy as np
 import pytest
 
 from bench.run import run_cell
-from bench.tests.tiny import tiny_cell
+from bench.tests.tiny import config_files, tiny_cell
 
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
 def state_unchanged(srv):
-    """Every decode step hands back the KV cache it was given."""
+    """Every decode step hands back the KV cache as it was before the step:
+    a copy, since the step takes its input cache donated."""
+    import jax
+    import jax.numpy as jnp
+
     decode = srv._decode
 
     def step(params, cache, token, pos, finite):
+        kept = jax.tree.map(jnp.copy, cache)
         token, finite, _ = decode(params, cache, token, pos, finite)
-        return token, finite, cache
+        return token, finite, kept
 
     srv._decode = step
 
@@ -48,9 +54,17 @@ def token_altered(srv):
 
 @pytest.mark.parametrize("fault", [state_unchanged, half_batch_left_out,
                                    token_altered])
-def test_fault_turns_correct_false(fault):
+@pytest.mark.parametrize("config", list(config_files()))
+def test_fault_turns_correct_false(config, fault):
     import time
 
-    out = run_cell(tiny_cell(), 2**31 + 11, 1.0, False,
+    out = run_cell(tiny_cell(config), 2**31 + 11, 1.0, False,
                    t_start=time.monotonic(), peaks=PEAKS, tamper=fault)
-    assert out["correct"] is False, out["checks"]
+    checks = out["checks"]
+    assert out["correct"] is False, checks
+    if fault is state_unchanged:
+        # a stale cache is caught by the values, every answer delivered
+        assert all(c["value"] == 0 for n, c in checks.items()
+                   if n != "logit_gap"), checks
+        assert checks["logit_gap"]["value"] > \
+            checks["logit_gap"]["limit"], checks

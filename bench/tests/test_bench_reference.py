@@ -1,22 +1,26 @@
 """The float32 reference against the served program's own steps, and the
-fp8 control against both, at the CPU's size."""
+fp8 control against both, at the CPU's size, for every configuration of
+``BENCHMARK.json`` through its architecture module."""
 import numpy as np
 import pytest
-from bench.tests.tiny import tiny_config
+from bench.tests.tiny import config_files, tiny_config
 
 from bench import check, reference, system
+from bench.harness import load_arch
 from bench.weights import make_weights
 
 PROMPT, GEN, ROWS, SEED = 24, 6, 3, 2**31 + 5
+CONFIGS = list(config_files())
 
 
 def _program(hf):
     import jax.numpy as jnp
     from repro.serving.qos_server import serving_steps
 
-    cfg = system.model_config(hf)
+    arch = load_arch(hf["model_type"])
+    cfg = arch.model_config(hf)
     model = system.build_model(cfg)
-    params = system.make_params(hf, cfg, SEED)
+    params = arch.make_params(hf, cfg, SEED)
     rng = np.random.default_rng(0)
     prompts = rng.integers(3, hf["vocab_size"], (ROWS, PROMPT), np.int32)
     max_len = PROMPT + GEN + 8
@@ -45,14 +49,15 @@ def _program(hf):
                       for x in out_logits], 1))
 
 
-@pytest.mark.parametrize("config", ["qwen3-1.7b", "yi-6b.l16"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_reference_matches_prefill_and_decode(config):
     hf = tiny_config(config)
+    arch = load_arch(hf["model_type"])
     prompts, served, steps, logits = _program(hf)
     np.testing.assert_array_equal(served, steps)
-    w = make_weights(hf, SEED)
+    w = arch.make_weights(hf, SEED)
     tokens = np.concatenate([prompts, served[:, :-1]], 1)
-    ref = np.asarray(reference.logits(hf, w, tokens, PROMPT - 1, GEN))
+    ref = np.asarray(arch.logits(hf, w, tokens, PROMPT - 1, GEN))
     assert ref.shape == logits.shape
     # the program computes in bfloat16 (8 bits of mantissa) and returns
     # bfloat16 logits: at this size they stay within 5% of the float32
@@ -61,17 +66,18 @@ def test_reference_matches_prefill_and_decode(config):
     spread = ref.std()
     assert np.abs(ref - logits).max() < 0.1 * spread, (
         np.abs(ref - logits).max(), spread)
-    gaps = check.gaps(hf, SEED, PROMPT, prompts, served)
+    gaps = check.gaps(arch, hf, SEED, PROMPT, prompts, served)
     assert gaps.shape == (ROWS, GEN)
     assert gaps.min() >= 0.0
 
 
-@pytest.mark.parametrize("config", ["qwen3-1.7b", "yi-6b.l16"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_fp8_control_reads_far_above_the_program(config):
     hf = tiny_config(config)
+    arch = load_arch(hf["model_type"])
     prompts, served, _, _ = _program(hf)
-    program = check.gaps(hf, SEED, PROMPT, prompts, served).max()
-    control = check.gaps(hf, SEED, PROMPT, prompts, served,
+    program = check.gaps(arch, hf, SEED, PROMPT, prompts, served).max()
+    control = check.gaps(arch, hf, SEED, PROMPT, prompts, served,
                          control=True).max()
     assert control >= 3.0 * program, (program, control)
 
